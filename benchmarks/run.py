@@ -22,6 +22,7 @@ from benchmarks import (appendix_d_search, bench_cascade, bench_coalesce,
                         table4_runtime_cost, table5_quality,
                         table6_optimizer_overhead, table7_judge,
                         table8_semantics_ablation, table9_smart)
+from repro.launch.compile_cache import enable_compile_cache
 
 BENCHES = [
     ("bench_coalesce", lambda q: bench_coalesce.run(
@@ -68,6 +69,7 @@ def main(argv=None):
                     help="run a single benchmark by name substring")
     common.add_driver_arg(ap)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.driver:
         common.set_driver(args.driver)
     if args.coalesce is not None:

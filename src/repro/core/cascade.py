@@ -89,14 +89,10 @@ class HashingEncoder:
 
 def _kernel_scores(vals: np.ndarray, anchor: np.ndarray) -> np.ndarray:
     """One batched device pass: rowwise cosine of every value embedding
-    against the (broadcast) anchor through the Pallas kernel; pure-numpy
-    fallback when jax is unavailable (missing-dep gate, not a perf path)."""
-    try:
-        from repro.kernels import ops as kops
-        tiled = np.broadcast_to(anchor, vals.shape)
-        return np.asarray(kops.rowwise_cosine(vals, tiled), np.float32)
-    except ImportError:
-        return np.asarray(vals @ anchor, np.float32)
+    against the (broadcast) anchor through the Pallas kernel."""
+    from repro.kernels import ops as kops
+    tiled = np.broadcast_to(anchor, vals.shape)
+    return np.asarray(kops.rowwise_cosine(vals, tiled), np.float32)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -96,7 +96,7 @@ def semantic_equal_batch(xs: Sequence, ys: Sequence,
                          threshold: float = SEM_EQ_THRESHOLD,
                          use_kernel: bool = True) -> np.ndarray:
     """Vectorized aligned-pair equality. Dispatches the cosine compute to
-    the Pallas kernel when available (ops handles CPU interpret fallback)."""
+    the Pallas kernel (``kernels.ops`` picks interpret mode on the CPU)."""
     if len(xs) != len(ys):
         raise ValueError("aligned sequences required")
     if not len(xs):
@@ -113,11 +113,8 @@ def semantic_equal_batch(xs: Sequence, ys: Sequence,
         a = embed([xs[i] for i in text_idx])
         b = embed([ys[i] for i in text_idx])
         if use_kernel:
-            try:
-                from repro.kernels import ops as kops
-                sims = np.asarray(kops.rowwise_cosine(a, b))
-            except Exception:
-                sims = np.sum(a * b, axis=1)
+            from repro.kernels import ops as kops
+            sims = np.asarray(kops.rowwise_cosine(a, b))
         else:
             sims = np.sum(a * b, axis=1)
         for j, i in enumerate(text_idx):

@@ -50,6 +50,7 @@ from __future__ import annotations
 import multiprocessing as mp
 import os
 import pickle
+import sys
 import threading
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
@@ -57,6 +58,29 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 from repro.core import backends as bk
 from repro.core import runtime as rt
+
+# the JAX platform every worker process is pinned to. No backend has this
+# name, so a JAX computation in a worker raises instead of taking the
+# coordinator's device (or quietly falling back to the CPU)
+NO_DEVICE_PLATFORM = "none-in-process-shard-workers"
+
+
+def _forbid_jax_backends() -> None:
+    """One process per device: the coordinator owns the accelerator, and
+    backends that need it (the engine-backed ``JAXBackend``, the cascade's
+    embedding pass) never ship. Pin this worker to a platform that does
+    not exist, both for a later ``import jax`` and for a JAX the parent's
+    ``__main__`` already imported; fail now if a backend is already up."""
+    os.environ["JAX_PLATFORMS"] = NO_DEVICE_PLATFORM
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return
+    from jax._src import xla_bridge
+    if xla_bridge.backends_are_initialized():
+        raise RuntimeError("a JAX backend was initialised in a process "
+                           "shard worker before its request loop started; "
+                           "only the coordinator may hold the device")
+    jax.config.update("jax_platforms", NO_DEVICE_PLATFORM)
 
 
 def shippable_backends(backends: Dict[str, Any]) -> Dict[str, Any]:
@@ -83,6 +107,7 @@ def _worker_main(conn, backends: Dict[str, Any], concurrency: int,
     Each request bills into a fresh meter that ships back with the reply.
     A heartbeat thread pings ``("hb",)`` every ``heartbeat_s`` so the
     coordinator can tell a stalled worker from a slow call."""
+    _forbid_jax_backends()
     send_lock = threading.Lock()
     stop = threading.Event()
 

@@ -56,6 +56,15 @@ def _batch_index(p: cm.Param) -> int:
     return p.axes.index("batch")
 
 
+def jitted_steps(bundle, *, max_len: int, dtype):
+    """The engine's two compiled programs: ``decode(params, cache, token)``
+    over every slot, and ``prefill(params, batch)`` for one request."""
+    decode = jax.jit(lambda p, c, t: bundle.decode_step(p, c, t, dtype=dtype))
+    prefill = jax.jit(
+        lambda p, b: bundle.prefill(p, b, max_len=max_len, dtype=dtype))
+    return decode, prefill
+
+
 class GenerationEngine:
     def __init__(self, bundle, params, *, max_len: int = 256,
                  n_slots: int = 4, dtype=jnp.float32,
@@ -71,10 +80,8 @@ class GenerationEngine:
         self.last_token = jnp.zeros((n_slots, 1), jnp.int32)
         self.active = np.zeros((n_slots,), bool)
         self.slot_req: List[Optional[Request]] = [None] * n_slots
-        self._decode_jit = jax.jit(
-            lambda p, c, t: bundle.decode_step(p, c, t, dtype=dtype))
-        self._prefill_jit = jax.jit(
-            lambda p, b: bundle.prefill(p, b, max_len=max_len, dtype=dtype))
+        self._decode_jit, self._prefill_jit = jitted_steps(
+            bundle, max_len=max_len, dtype=dtype)
         self.stats = {"decode_steps": 0, "prefills": 0, "occupancy_sum": 0.0,
                       "decode_s": 0.0, "prefill_s": 0.0}
 
@@ -92,9 +99,12 @@ class GenerationEngine:
         req.output_ids = []
         req.slot = slot
         tokens = self.tok.pad_batch([ids], align=PREFILL_ALIGN)
-        logits, cache1 = self._prefill_jit(self.params,
-                                           {"tokens": jnp.asarray(tokens)})
-        # prefill padded the prompt; the next position is len(ids)
+        # prefill right-pads the prompt: the first token is predicted from
+        # the last real position, and the next cache position is len(ids)
+        logits, cache1 = self._prefill_jit(
+            self.params, {"tokens": jnp.asarray(tokens),
+                          "last_index": jnp.asarray([len(ids) - 1],
+                                                    jnp.int32)})
         pos_next = len(ids)
 
         def splice(dst: cm.Param, src: cm.Param) -> cm.Param:

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 NEG_INF = -1e30
 DEFAULT_BK = 512
 
@@ -103,7 +101,7 @@ def decode_attention(q, k_cache, v_cache, cache_len, *,
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hq, 1, d), q.dtype),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(cache_len, q, k_cache, v_cache)

@@ -2,8 +2,9 @@
 
 Handles layout adaptation (models use (B, S, H, D); kernels want
 (B, H, S, D)), padding to block multiples, and backend dispatch: on TPU the
-kernels compile natively; on CPU (this container) they run in interpret
-mode so tests validate the exact kernel bodies against the ref.py oracles.
+kernels compile natively; on CPU they run in interpret mode so tests
+validate the exact kernel bodies against the ref.py oracles. Any other
+backend raises.
 """
 from __future__ import annotations
 
@@ -21,8 +22,16 @@ from repro.kernels import ssd_scan as ssd_mod
 NEG_INF = -1e30
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def interpret_mode() -> bool:
+    """Interpret mode on the CPU only; any other non-TPU backend raises
+    rather than silently running the kernels in the interpreter."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"Pallas TPU kernels cannot run on backend "
+                       f"{backend!r}; use a TPU or JAX_PLATFORMS=cpu")
 
 
 def _pad_axis(x, axis: int, mult: int):
@@ -57,7 +66,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     out = fa_mod.flash_attention(
         qt, kt, vt, causal=causal, window=window,
         q_offset=(sk0 - sq0) if causal else 0, sk_valid=sk0, bq=bq, bk=bk,
-        interpret=_interpret())
+        interpret=interpret_mode())
     out = out[:, :, :sq0]
     return jnp.moveaxis(out, 1, 2)
 
@@ -80,7 +89,7 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, bk: int = 0):
     vt, _ = _pad_axis(vt, 2, bk)
     cl = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32), (b,))
     out = dec_mod.decode_attention(qt, kt, vt, cl, bk=bk,
-                                   interpret=_interpret())
+                                   interpret=interpret_mode())
     return jnp.moveaxis(out, 1, 2)
 
 
@@ -96,28 +105,38 @@ def ssd_scan(dx, dA, B, C, initial_state=None, *, chunk: int = 0):
     while s % chunk:
         chunk //= 2
     return ssd_mod.ssd_scan(dx, dA, B, C, initial_state, chunk=chunk,
-                            interpret=_interpret())
+                            interpret=interpret_mode())
 
 
 # ---------------------------------------------------------------------------
 # Similarity (improvement score / judge)
 # ---------------------------------------------------------------------------
 
-def cosine_matrix(a, b):
-    """(M, D) x (N, D) -> (M, N) fp32 cosine (rows pre-normalized)."""
-    a = jnp.asarray(a)
-    b = jnp.asarray(b)
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def cosine_matrix_jit(a, b, *, interpret: bool):
+    """The compiled program behind :func:`cosine_matrix`."""
     a, m0 = _pad_axis(a, 0, sim_mod.BM)
     b, n0 = _pad_axis(b, 0, sim_mod.BN)
-    out = sim_mod.cosine_matrix(a, b, interpret=_interpret())
-    return np.asarray(out[:m0, :n0])
+    out = sim_mod.cosine_matrix(a, b, interpret=interpret)
+    return out[:m0, :n0]
+
+
+def cosine_matrix(a, b):
+    """(M, D) x (N, D) -> (M, N) fp32 cosine (rows pre-normalized)."""
+    return np.asarray(cosine_matrix_jit(jnp.asarray(a), jnp.asarray(b),
+                                        interpret=interpret_mode()))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def rowwise_cosine_jit(a, b, *, interpret: bool):
+    """The compiled program behind :func:`rowwise_cosine`."""
+    a, m0 = _pad_axis(a, 0, sim_mod.BM)
+    b, _ = _pad_axis(b, 0, sim_mod.BM)
+    out = sim_mod.rowwise_cosine(a, b, interpret=interpret)
+    return out[:m0]
 
 
 def rowwise_cosine(a, b):
     """Aligned pairs (M, D), (M, D) -> (M,) fp32 cosine."""
-    a = jnp.asarray(a)
-    b = jnp.asarray(b)
-    a, m0 = _pad_axis(a, 0, sim_mod.BM)
-    b, _ = _pad_axis(b, 0, sim_mod.BM)
-    out = sim_mod.rowwise_cosine(a, b, interpret=_interpret())
-    return np.asarray(out[:m0])
+    return np.asarray(rowwise_cosine_jit(jnp.asarray(a), jnp.asarray(b),
+                                         interpret=interpret_mode()))

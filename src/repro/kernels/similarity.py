@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 BM = 128
 BN = 128
 
@@ -66,7 +64,7 @@ def cosine_matrix(a, b, *, bm: int = BM, bn: int = BN,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(a, b)
@@ -97,7 +95,7 @@ def rowwise_cosine(a, b, *, bm: int = BM, interpret: bool = False):
         ],
         out_specs=pl.BlockSpec((bm, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, 1), jnp.float32),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(a, b)

@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 DEFAULT_CHUNK = 256
 
 
@@ -109,7 +107,7 @@ def ssd_scan(dx, dA, B, C, initial_state=None, *,
             jax.ShapeDtypeStruct((b, h, n, p), jnp.float32),
         ),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(dx, dA3, B, C, initial_state)

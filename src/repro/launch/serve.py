@@ -3,9 +3,8 @@ reference with semantics and quickstarts: ``docs/SERVING.md``.
 
 **Token serving** (default; no ``--semantic``): continuous-batching
 generation over a zoo model — reports throughput, slot occupancy, and
-per-request latency percentiles. Full-size configs are proven via
-launch/dryrun.py (decode cells lower the same decode_step this engine
-drives)::
+per-request latency percentiles. ``--no-reduced`` serves the published
+widths (on a TPU; ``chip_smoke.py`` drives it there)::
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b \\
         --reduced --requests 16 --slots 4 --max-new 24
@@ -63,10 +62,12 @@ import argparse
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, reduced as reduce_cfg
 from repro.engine import ContinuousBatcher, GenerationEngine
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import registry
 
 DEMO_PROMPTS = [
@@ -78,7 +79,20 @@ DEMO_PROMPTS = [
 ]
 
 
-def _semantic_context(args):
+def model_config(arch: str, reduced: bool):
+    """The zoo config ``arch``, cut to the CPU-sized ``reduced`` variant
+    unless ``--no-reduced`` asked for the published widths."""
+    cfg = get_config(arch)
+    return reduce_cfg(cfg) if reduced else cfg
+
+
+def semantic_config(args):
+    """The engine config of the semantic modes: the m1 tier's model."""
+    from repro.core.cost_model import DEFAULT_TIERS
+    return model_config(DEFAULT_TIERS["m1"].arch, args.reduced)
+
+
+def semantic_context(args):
     """Build the engine-backed ExecutionContext both semantic modes use:
     the default tier (m1) is served by THIS engine in oracle-echo mode,
     the other tiers stay simulated."""
@@ -90,7 +104,7 @@ def _semantic_context(args):
 
     table, oracle = load_dataset(args.semantic, max_rows=args.requests * 4)
     tier = DEFAULT_TIERS["m1"]
-    cfg = reduce_cfg(get_config(tier.arch))
+    cfg = semantic_config(args)
     bundle = registry.build(cfg)
     params = bundle.init(jax.random.PRNGKey(args.seed))
     engine = GenerationEngine(bundle, params, max_len=args.max_len,
@@ -152,14 +166,15 @@ def serve_semantic(args):
     from repro.core import runtime as rt
     from repro.data import WORKLOADS
 
-    table, cfg, engine, ctx = _semantic_context(args)
+    table, cfg, engine, ctx = semantic_context(args)
     if args.serve > 0:
         out = serve_queries(args, table, cfg, engine, ctx)
         _explain_cost(args, ctx)
         return out
     q = WORKLOADS[args.semantic][0]
     print(f"[serve] semantic query {q.qid} over {table.name} "
-          f"({table.n_rows} rows), m1 = {cfg.name} on {args.slots} slots, "
+          f"({table.n_rows} rows), m1 = {cfg.name} "
+          f"({jnp.dtype(engine.dtype).name}) on {args.slots} slots, "
           f"driver={args.driver} shards={args.shards} procs={args.procs} "
           f"batch={args.batch} "
           f"coalesce={args.coalesce} linger={args.linger} "
@@ -225,21 +240,24 @@ def parse_admission(spec: str):
     return AdmissionController(**kw)
 
 
-def serve_queries(args, table, cfg, engine, ctx):
+def serve_queries(args, table, cfg, engine, ctx, queries=None):
     """Streaming semantic serve: admit ``--serve N`` workload queries
     (staggered by ``--stagger``) onto one shared QueryServer and report
     per-query latency percentiles + makespan vs sequential estimate.
     With ``--admission`` the queries route through the multi-tenant
-    admission controller (``--tenants/--lane/--slo`` shape the load)."""
+    admission controller (``--tenants/--lane/--slo`` shape the load).
+    ``queries`` replaces the dataset's first N workload queries."""
     from repro.data import WORKLOADS
     from repro.launch.query_server import QueryServer
 
-    queries = [WORKLOADS[args.semantic][i % len(WORKLOADS[args.semantic])]
-               for i in range(args.serve)]
+    if queries is None:
+        wl = WORKLOADS[args.semantic]
+        queries = [wl[i % len(wl)] for i in range(args.serve)]
     offsets = stagger_offsets(len(queries), args.stagger, seed=args.seed)
     controller = parse_admission(args.admission)
     print(f"[serve] streaming {len(queries)} queries over {table.name} "
-          f"({table.n_rows} rows), m1 = {cfg.name} on {args.slots} slots, "
+          f"({table.n_rows} rows), m1 = {cfg.name} "
+          f"({jnp.dtype(engine.dtype).name}) on {args.slots} slots, "
           f"driver={args.driver} shards={args.shards} procs={args.procs} "
           f"batch={args.batch} stagger={args.stagger}s "
           f"tenants={args.tenants} lane={args.lane} "
@@ -406,20 +424,19 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
 
     if args.semantic:
         return serve_semantic(args)
 
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = reduce_cfg(cfg)
+    cfg = model_config(args.arch, args.reduced)
     bundle = registry.build(cfg)
     params = bundle.init(jax.random.PRNGKey(args.seed))
-    print(f"[serve] arch={cfg.name} params={cfg.param_count()/1e6:.2f}M "
-          f"slots={args.slots} max_len={args.max_len}")
-
     engine = GenerationEngine(bundle, params, max_len=args.max_len,
                               n_slots=args.slots)
+    print(f"[serve] arch={cfg.name} params={cfg.param_count()/1e6:.2f}M "
+          f"dtype={jnp.dtype(engine.dtype).name} "
+          f"slots={args.slots} max_len={args.max_len}")
     batcher = ContinuousBatcher(engine)
     t0 = time.time()
     for i in range(args.requests):

@@ -177,9 +177,8 @@ def moe_forward_shardmap(p, x, cfg, mesh, *, dp_axes=("data",),
 
     x_spec = P(dp_axes, None, None)
     shared = p.get("shared")
-    from repro.kernels import compat
-    fn = compat.shard_map(
-        local_fn, mesh,
+    fn = jax.shard_map(
+        local_fn, mesh=mesh,
         in_specs=(x_spec, P(None, None), P(ep_axis, None, None),
                   P(ep_axis, None, None), P(ep_axis, None, None),
                   None if shared is None else jax.tree.map(

@@ -50,7 +50,8 @@ def _build_decoder(cfg: ModelConfig) -> ModelBundle:
     def prefill_fn(params, batch, max_len=None, *, dtype=jnp.bfloat16):
         return transformer.prefill(params, cfg, batch["tokens"],
                                    prefix_embeds=batch.get("prefix_embeds"),
-                                   max_len=max_len, dtype=dtype)
+                                   max_len=max_len, dtype=dtype,
+                                   last_index=batch.get("last_index"))
 
     def decode_fn(params, cache, token, *, dtype=jnp.bfloat16):
         return transformer.decode_step(params, cfg, cache, token,

@@ -322,11 +322,15 @@ def decode_step(params, cfg: ModelConfig, cache, token, *,
 
 
 def prefill(params, cfg: ModelConfig, tokens, *, prefix_embeds=None,
-            max_len: Optional[int] = None, dtype=jnp.bfloat16):
+            max_len: Optional[int] = None, dtype=jnp.bfloat16,
+            last_index=None):
     """Run the full-sequence forward while building the decode cache.
 
     Returns (last-position logits, cache). Implemented as a scan over layers
     mirroring `forward` but capturing K/V (or SSM state) per layer.
+    ``last_index`` (B,) int32 picks, per row, the position whose logits are
+    returned — the last real token of a right-padded prompt; None = the
+    final position.
     """
     x = embed_inputs(params, cfg, tokens, prefix_embeds, dtype)
     b, seq = x.shape[0], x.shape[1]
@@ -381,7 +385,12 @@ def prefill(params, cfg: ModelConfig, tokens, *, prefix_embeds=None,
 
     x, cache_stk = jax.lax.scan(body, x, (params["layers"], windows))
     x = cm.rmsnorm(params["final_norm"], x, cfg.rms_eps)
-    logits_last = unembed(params, cfg, x[:, -1:])
+    if last_index is None:
+        x_last = x[:, -1:]
+    else:
+        x_last = jnp.take_along_axis(
+            x, jnp.asarray(last_index, jnp.int32)[:, None, None], axis=1)
+    logits_last = unembed(params, cfg, x_last)
 
     axes_map = {
         "k": ("layer", "batch", "kv_seq", "kv_heads", "head_dim"),

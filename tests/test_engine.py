@@ -40,6 +40,25 @@ def test_continuous_batching_matches_sequential(served):
         assert got[rid].output_ids == w, rid
 
 
+@pytest.mark.parametrize("n_tokens", [17, 33, 65])
+def test_first_token_from_last_real_position(served, n_tokens):
+    """Prefill right-pads to a multiple of PREFILL_ALIGN; the first
+    generated token must come from the last real prompt position, i.e.
+    equal the argmax of an unpadded prefill of the same prompt."""
+    _, bundle, params = served
+    prompt = "".join(chr(97 + (7 * i) % 26) for i in range(n_tokens - 1))
+    ids = ByteTokenizer().encode(prompt)
+    assert len(ids) == n_tokens
+    logits, _ = bundle.prefill(params, {"tokens": jnp.asarray([ids])},
+                               max_len=len(ids), dtype=jnp.float32)
+    want = int(jnp.argmax(logits[0, -1]))
+
+    eng = GenerationEngine(bundle, params, max_len=96, n_slots=2)
+    cb = ContinuousBatcher(eng)
+    rid = cb.submit(prompt, max_new_tokens=1)
+    assert cb.run()[rid].output_ids == [want]
+
+
 def test_more_requests_than_slots(served):
     _, bundle, params = served
     eng = GenerationEngine(bundle, params, max_len=64, n_slots=2)

@@ -217,3 +217,16 @@ def test_semhash_uses_kernel_path():
     eq2 = semhash.semantic_equal_batch(xs, ys, use_kernel=False)
     assert list(eq) == list(eq2)
     assert eq[0]          # identical strings
+
+
+@pytest.mark.parametrize("backend,interpret", [
+    ("cpu", True), ("tpu", False), ("gpu", None)])
+def test_interpret_mode_only_on_cpu(monkeypatch, backend, interpret):
+    """Interpret mode on the CPU, native on the TPU; any other backend
+    raises instead of quietly running the kernels in the interpreter."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="cannot run on backend"):
+            ops.interpret_mode()
+    else:
+        assert ops.interpret_mode() is interpret

@@ -17,7 +17,9 @@ from repro.core import executor as ex
 from repro.core import plan as plan_ir
 from repro.core import runtime as rt
 from repro.distributed.morsel_shards import ShardedDispatcher, _compose
-from repro.distributed.process_workers import (ProcessShardDispatcher,
+from repro.distributed.process_workers import (NO_DEVICE_PLATFORM,
+                                               ProcessShardClient,
+                                               ProcessShardDispatcher,
                                                shippable_backends)
 
 pytestmark = pytest.mark.proc
@@ -304,6 +306,35 @@ def test_proc_unpicklable_backends_stay_coordinator_side():
     assert tg.result_fingerprint(res) == tg.result_fingerprint(res_ref)
     assert _totals(m) == _totals(m_ref)
     assert sum(s["llm"] for s in stats) == 0      # nothing went remote
+
+
+class JaxTouchingBackend(tg.SleepBackend):
+    """Runs a JAX computation inside ``run_values``."""
+
+    def run_values(self, op, values, meter=None, batch_size=1):
+        import jax.numpy as jnp
+        float(jnp.ones(2).sum())
+        return super().run_values(op, values, meter=meter,
+                                  batch_size=batch_size)
+
+
+def test_proc_worker_never_initializes_a_jax_backend():
+    """Workers are pinned to a JAX platform that does not exist: a JAX
+    computation shipped to one fails loudly (and never falls back to the
+    CPU or takes the coordinator's device), while the same backend runs
+    in the coordinator."""
+    backend = JaxTouchingBackend(tg.KindOracle(), delay_s=0.0)
+    op = plan_ir.Operator(plan_ir.MAP, "annotate", "v", "a")
+    assert backend.run_values(op, ["x"])              # coordinator: fine
+    client = ProcessShardClient({"m*": backend}, 1)
+    try:
+        client.wait_ready()
+        tag, err, _ = client.call("llm", ("m*", op, ["x"], 1, None, None))
+    finally:
+        client.close()
+    assert tag == "err"
+    assert isinstance(err, RuntimeError)
+    assert NO_DEVICE_PLATFORM in str(err)
 
 
 # -- occupancy (satellite bugfix) ------------------------------------------

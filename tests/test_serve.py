@@ -417,6 +417,46 @@ def test_serve_parser_and_stagger_offsets():
     assert serve.stagger_offsets(3, 0.0) == [0.0, 0.0, 0.0]
 
 
+@pytest.mark.parametrize("argv,full", [
+    (["--semantic", "movie"], False),
+    (["--semantic", "movie", "--reduced"], False),
+    (["--semantic", "movie", "--no-reduced"], True),
+])
+def test_serve_semantic_honours_no_reduced(argv, full):
+    """The semantic modes serve the m1 tier's model, at the published
+    widths under --no-reduced (config chosen without building it)."""
+    from repro.configs import get_config
+    from repro.core.cost_model import DEFAULT_TIERS
+    from repro.launch import serve
+    cfg = serve.semantic_config(serve.build_parser().parse_args(argv))
+    published = get_config(DEFAULT_TIERS["m1"].arch)
+    assert published.name == "qwen2-0.5b"
+    assert (cfg == published) is full
+    if full:
+        assert (cfg.n_layers, cfg.d_model, cfg.vocab_size) == \
+            (24, 896, 151936)
+    else:
+        assert cfg.d_model < published.d_model
+
+
+def test_compile_cache_placed_from_env_or_checkout(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR, when set, is JAX's own setting and left
+    alone; otherwise the cache goes to one fixed path in the checkout."""
+    import jax
+    from repro.launch import compile_cache as cc
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.append((k, v)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "cache-from-env")
+    assert cc.enable_compile_cache() == "cache-from-env"
+    assert updates == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert cc.enable_compile_cache() == str(cc.REPO_CACHE_DIR)
+    assert updates == [("jax_compilation_cache_dir", str(cc.REPO_CACHE_DIR))]
+    assert cc.REPO_CACHE_DIR.name == ".jax_cache"
+    assert (cc.REPO_CACHE_DIR.parent / "pyproject.toml").is_file()
+
+
 def test_serve_submit_after_close_is_rejected():
     ctx, _ = _ctx()
     server = QueryServer(ctx)
