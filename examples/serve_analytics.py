@@ -45,7 +45,7 @@ def main():
     res = report.result
     print("\nresult:", repr(res)[:160])
     print(f"\nreal serving stats: {engine.stats['prefills']} prefills, "
-          f"{engine.stats['decode_steps']} decode ticks, "
+          f"{engine.stats['ticks']} decode ticks, "
           f"occupancy={engine.occupancy:.2f}")
     for tier_name, u in report.execution.meter.by_tier.items():
         print(f"  exec[{tier_name}]: calls={u.calls} "

@@ -15,6 +15,11 @@ Slot-based runtime in the vLLM mold, adapted to JAX/TPU:
 The engine is architecture-agnostic: GQA / MLA KV caches and SSM / hybrid
 recurrent states all flow through the same Param-tree insert because cache
 leaves carry their logical axes ("batch" marks the slot dim).
+
+Each insert and tick is a ``jax.profiler.TraceAnnotation`` span named
+``engine.*``, with a child span per phase, so a profiler trace shows which
+part of the host path leaves the device idle; a span costs an enabled-check
+when no profiler runs. ``GenerationEngine.stats`` counts the same work.
 """
 from __future__ import annotations
 
@@ -25,6 +30,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.data.tokenizer import ByteTokenizer
 from repro.models import common as cm
@@ -42,7 +48,6 @@ class Request:
     prompt_ids: Optional[list] = None
     output_ids: Optional[list] = None
     slot: int = -1
-    prefill_s: float = 0.0
     submitted_s: float = 0.0
     started_s: float = 0.0      # slot insert (service start, not enqueue)
     done_s: float = 0.0
@@ -57,12 +62,17 @@ def _batch_index(p: cm.Param) -> int:
 
 
 def jitted_steps(bundle, *, max_len: int, dtype):
-    """The engine's two compiled programs: ``decode(params, cache, token)``
-    over every slot, and ``prefill(params, batch)`` for one request."""
-    decode = jax.jit(lambda p, c, t: bundle.decode_step(p, c, t, dtype=dtype))
-    prefill = jax.jit(
-        lambda p, b: bundle.prefill(p, b, max_len=max_len, dtype=dtype))
-    return decode, prefill
+    """The engine's two compiled programs: ``engine_decode(params, cache,
+    token)`` over every slot, and ``engine_prefill(params, batch)`` for one
+    request. Their names name the modules in a device trace
+    (``jit_engine_decode``, ``jit_engine_prefill``)."""
+    def engine_decode(params, cache, token):
+        return bundle.decode_step(params, cache, token, dtype=dtype)
+
+    def engine_prefill(params, batch):
+        return bundle.prefill(params, batch, max_len=max_len, dtype=dtype)
+
+    return jax.jit(engine_decode), jax.jit(engine_prefill)
 
 
 class GenerationEngine:
@@ -82,8 +92,12 @@ class GenerationEngine:
         self.slot_req: List[Optional[Request]] = [None] * n_slots
         self._decode_jit, self._prefill_jit = jitted_steps(
             bundle, max_len=max_len, dtype=dtype)
-        self.stats = {"decode_steps": 0, "prefills": 0, "occupancy_sum": 0.0,
-                      "decode_s": 0.0, "prefill_s": 0.0}
+        # ticks; live_slot_ticks: live slots summed over ticks (each a token
+        # appended to a request, besides the first, at insert);
+        # context_tokens: positions the live slots attend over, summed over
+        # ticks (prompt and output so far, the token written included)
+        self.stats = {"ticks": 0, "live_slot_ticks": 0, "prefills": 0,
+                      "prompt_tokens": 0, "context_tokens": 0}
 
     # ------------------------------------------------------------------
     def free_slots(self) -> List[int]:
@@ -92,20 +106,11 @@ class GenerationEngine:
     def insert(self, req: Request, slot: int) -> Optional[Request]:
         """Prefill one request and splice it into the slot batch. Returns
         the request if it finished at prefill (prompt fills the window)."""
-        t0 = time.perf_counter()
-        req.started_s = t0
+        req.started_s = time.perf_counter()
         ids = self.tok.encode(req.prompt)[: self.max_len - 1]
         req.prompt_ids = ids
         req.output_ids = []
         req.slot = slot
-        tokens = self.tok.pad_batch([ids], align=PREFILL_ALIGN)
-        # prefill right-pads the prompt: the first token is predicted from
-        # the last real position, and the next cache position is len(ids)
-        logits, cache1 = self._prefill_jit(
-            self.params, {"tokens": jnp.asarray(tokens),
-                          "last_index": jnp.asarray([len(ids) - 1],
-                                                    jnp.int32)})
-        pos_next = len(ids)
 
         def splice(dst: cm.Param, src: cm.Param) -> cm.Param:
             if dst.axes == ("batch",) or dst.axes == ():   # pos vector
@@ -117,16 +122,28 @@ class GenerationEngine:
                 dst.value, src.value.astype(dst.value.dtype), tuple(idx)),
                 dst.axes)
 
-        self.cache = jax.tree.map(splice, self.cache, cache1,
-                                  is_leaf=cm.is_param)
-        pos = self.cache["pos"].value.at[slot].set(pos_next)
-        self.cache["pos"] = cm.Param(pos, ("batch",))
-        nxt = jnp.argmax(logits[0, -1]).astype(jnp.int32)
-        self.last_token = self.last_token.at[slot, 0].set(nxt)
-        req.output_ids.append(int(nxt))
+        with TraceAnnotation("engine.insert", rid=req.rid, slot=slot,
+                             prompt_len=len(ids)):
+            tokens = self.tok.pad_batch([ids], align=PREFILL_ALIGN)
+            # prefill right-pads the prompt: the first token is predicted
+            # from the last real position; the next cache position is
+            # len(ids)
+            with TraceAnnotation("engine.prefill"):
+                logits, cache1 = self._prefill_jit(
+                    self.params, {"tokens": jnp.asarray(tokens),
+                                  "last_index": jnp.asarray([len(ids) - 1],
+                                                            jnp.int32)})
+            with TraceAnnotation("engine.splice"):
+                self.cache = jax.tree.map(splice, self.cache, cache1,
+                                          is_leaf=cm.is_param)
+                pos = self.cache["pos"].value.at[slot].set(len(ids))
+                self.cache["pos"] = cm.Param(pos, ("batch",))
+            with TraceAnnotation("engine.first_token"):
+                nxt = jnp.argmax(logits[0, -1]).astype(jnp.int32)
+                self.last_token = self.last_token.at[slot, 0].set(nxt)
+                req.output_ids.append(int(nxt))
         self.stats["prefills"] += 1
-        req.prefill_s = time.perf_counter() - t0
-        self.stats["prefill_s"] += req.prefill_s
+        self.stats["prompt_tokens"] += len(ids)
         if (len(ids) + 1 >= self.max_len
                 or len(req.output_ids) >= req.max_new_tokens):
             req.done_s = time.perf_counter()
@@ -137,53 +154,62 @@ class GenerationEngine:
 
     def decode_tick(self, key=None) -> List[Request]:
         """One decode step across all slots; returns finished requests."""
-        t0 = time.perf_counter()
-        logits, self.cache = self._decode_jit(self.params, self.cache,
-                                              self.last_token)
-        # keep idle slots parked at position 0 (their writes are overwritten
-        # by the next insert; parking avoids pos growing past max_len)
-        pos = self.cache["pos"].value
-        pos = jnp.where(jnp.asarray(self.active), pos, 0)
-        pos = jnp.minimum(pos, self.max_len - 1)
-        self.cache["pos"] = cm.Param(pos, ("batch",))
+        live = int(self.active.sum())
+        with TraceAnnotation("engine.tick", live=live):
+            with TraceAnnotation("engine.decode"):
+                logits, self.cache = self._decode_jit(
+                    self.params, self.cache, self.last_token)
+            # keep idle slots parked at position 0 (their writes are
+            # overwritten by the next insert; parking avoids pos growing
+            # past max_len)
+            pos = self.cache["pos"].value
+            pos = jnp.where(jnp.asarray(self.active), pos, 0)
+            pos = jnp.minimum(pos, self.max_len - 1)
+            self.cache["pos"] = cm.Param(pos, ("batch",))
 
-        nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-        if key is not None:
-            temps = np.array([self.slot_req[i].temperature
-                              if self.slot_req[i] else 0.0
-                              for i in range(self.n_slots)], np.float32)
-            if (temps > 0).any():
-                g = jax.random.gumbel(key, logits[:, -1].shape)
-                samp = jnp.argmax(
-                    logits[:, -1] / jnp.maximum(temps[:, None], 1e-6) + g,
-                    axis=-1).astype(jnp.int32)
-                nxt = jnp.where(jnp.asarray(temps > 0), samp, nxt)
-        self.last_token = nxt[:, None]
-        self.stats["decode_steps"] += 1
-        self.stats["occupancy_sum"] += float(self.active.mean())
-        self.stats["decode_s"] += time.perf_counter() - t0
+            nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+            if key is not None:
+                temps = np.array([self.slot_req[i].temperature
+                                  if self.slot_req[i] else 0.0
+                                  for i in range(self.n_slots)], np.float32)
+                if (temps > 0).any():
+                    g = jax.random.gumbel(key, logits[:, -1].shape)
+                    samp = jnp.argmax(
+                        logits[:, -1] / jnp.maximum(temps[:, None], 1e-6)
+                        + g, axis=-1).astype(jnp.int32)
+                    nxt = jnp.where(jnp.asarray(temps > 0), samp, nxt)
+            self.last_token = nxt[:, None]
 
-        done: List[Request] = []
-        nxt_host = np.asarray(nxt)
-        for i in range(self.n_slots):
-            req = self.slot_req[i]
-            if req is None or not self.active[i]:
-                continue
-            req.output_ids.append(int(nxt_host[i]))
-            eos = nxt_host[i] == self.tok.eos_id
-            full = len(req.output_ids) >= req.max_new_tokens
-            over = len(req.prompt_ids) + len(req.output_ids) >= self.max_len
-            if eos or full or over:
-                req.done_s = time.perf_counter()
-                self.active[i] = False
-                self.slot_req[i] = None
-                done.append(req)
+            with TraceAnnotation("engine.tick_sync"):
+                nxt_host = np.asarray(nxt)
+            done: List[Request] = []
+            context = 0
+            with TraceAnnotation("engine.tick_update"):
+                for i in range(self.n_slots):
+                    req = self.slot_req[i]
+                    if req is None or not self.active[i]:
+                        continue
+                    context += len(req.prompt_ids) + len(req.output_ids)
+                    req.output_ids.append(int(nxt_host[i]))
+                    eos = nxt_host[i] == self.tok.eos_id
+                    full = len(req.output_ids) >= req.max_new_tokens
+                    over = (len(req.prompt_ids) + len(req.output_ids)
+                            >= self.max_len)
+                    if eos or full or over:
+                        req.done_s = time.perf_counter()
+                        self.active[i] = False
+                        self.slot_req[i] = None
+                        done.append(req)
+        self.stats["ticks"] += 1
+        self.stats["live_slot_ticks"] += live
+        self.stats["context_tokens"] += context
         return done
 
     @property
     def occupancy(self) -> float:
-        n = max(1, self.stats["decode_steps"])
-        return self.stats["occupancy_sum"] / n
+        """Mean share of the slots that were live over the ticks."""
+        n = max(1, self.stats["ticks"]) * self.n_slots
+        return self.stats["live_slot_ticks"] / n
 
 
 class ContinuousBatcher:

@@ -17,6 +17,10 @@ ONE shared :class:`ContinuousBatcher` and then cooperate on driving it —
 each takes the backend lock for a single ``step()`` at a time — so
 concurrent operators' requests genuinely share the engine's decode slots
 (continuous batching across callers) instead of corrupting the KV cache.
+
+``stats`` counts the calls, their seconds, and the seconds callers spent
+blocked on the backend lock; each call is also a
+``jax.profiler.TraceAnnotation`` span (``engine.call``).
 """
 from __future__ import annotations
 
@@ -24,6 +28,8 @@ import dataclasses
 import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence
+
+from jax.profiler import TraceAnnotation
 
 from repro.core import backends as bk
 from repro.core import cost as cost_mod
@@ -52,17 +58,38 @@ class JAXBackend:
         compare=False)
     _batcher: Optional[ContinuousBatcher] = dataclasses.field(
         default=None, init=False, repr=False, compare=False)
+    # calls: finished calls; call_s: seconds from each call's start to the
+    # collection of its requests; lock_wait_s: seconds blocked acquiring
+    # the lock. Written only while the lock is held.
+    stats: Dict[str, float] = dataclasses.field(
+        default_factory=lambda: {"calls": 0, "call_s": 0.0,
+                                 "lock_wait_s": 0.0},
+        init=False, repr=False, compare=False)
+
+    def _wait_for_lock(self) -> None:
+        """Block on the backend lock, found taken, and count the wait once
+        it is held. Callers first try ``self._lock.acquire(False)``: an
+        untaken lock is then taken with no clock read, so the counting
+        leaves unchanged which caller gets the lock."""
+        t0 = time.perf_counter()
+        self._lock.acquire()
+        self.stats["lock_wait_s"] += time.perf_counter() - t0
 
     def _submit(self, prompts: Sequence[str]) -> List[int]:
-        with self._lock:
+        if not self._lock.acquire(False):
+            self._wait_for_lock()
+        try:
             if self._batcher is None:
                 self._batcher = ContinuousBatcher(self.engine)
             return [self._batcher.submit(p,
                                          max_new_tokens=self.max_new_tokens)
                     for p in prompts]
+        finally:
+            self._lock.release()
 
-    def _collect(self, rids: Sequence[int]) -> Dict[int, Any]:
-        """Drive the shared batcher until this caller's requests finish.
+    def _collect(self, rids: Sequence[int], t0: float) -> Dict[int, Any]:
+        """Drive the shared batcher until this caller's requests finish,
+        and count the call (begun at ``t0``) once they have.
 
         Concurrent callers cooperate: whoever holds the lock advances the
         engine by one ``step`` (slot refill + one decode tick), then
@@ -70,54 +97,61 @@ class JAXBackend:
         requests join the same slot batch."""
         pending = set(rids)
         out: Dict[int, Any] = {}
-        while pending:
-            with self._lock:
+        while True:
+            if not self._lock.acquire(False):
+                self._wait_for_lock()
+            try:
                 for r in list(pending):
                     req = self._batcher.finished.pop(r, None)
                     if req is not None:
                         out[r] = req
                         pending.discard(r)
-                if pending:
-                    self._batcher.step()
-        return out
+                if not pending:
+                    self.stats["calls"] += 1
+                    self.stats["call_s"] += time.perf_counter() - t0
+                    return out
+                self._batcher.step()
+            finally:
+                self._lock.release()
 
     def run_values(self, op: plan_ir.Operator, values: Sequence[Any],
                    meter: Optional[bk.UsageMeter] = None,
                    batch_size: int = 1) -> List[Any]:
         t0 = time.perf_counter()
-        if op.kind == plan_ir.REDUCE:
-            joined = "; ".join(str(v)[:60] for v in list(values)[:32])
-            prompts = [render_prompt(op, joined)]
-        else:
-            prompts = [render_prompt(op, v) for v in values]
-
-        rids = self._submit(prompts)
-        finished = self._collect(rids)
-        raw = [finished[r].text for r in rids]
-
-        wall = time.perf_counter() - t0  # noqa: F841 — true batch wall
-        tok_in = sum(cost_mod.text_tokens(p) for p in prompts)
-        tok_out = sum(len(finished[r].output_ids or []) for r in rids)
-        if meter is not None:
-            # per-call latencies are the *measured* per-request SERVICE
-            # times (slot insert -> done) from the continuous batcher; the
-            # event scheduler re-queues jobs itself, so sojourn time
-            # (submit -> done) would double-count the slot-queue wait
-            per_call = [max(0.0, finished[r].done_s
-                            - (finished[r].started_s
-                               or finished[r].submitted_s))
-                        for r in rids]
-            meter.record(self.tier.name, bk.Usage(
-                calls=len(prompts), tok_in=tok_in, tok_out=tok_out,
-                usd=self.tier.usd(tok_in, tok_out),
-                latency_s=sum(per_call)),
-                per_call_latency_s=per_call, op_kind=op.kind)
-
-        if self.oracle is not None:
+        with TraceAnnotation("engine.call", kind=op.kind, rows=len(values)):
             if op.kind == plan_ir.REDUCE:
-                return [self.oracle.answer_reduce(op, values)]
-            return [self.oracle.answer(op, v) for v in values]
-        return self._parse(op, raw, values)
+                joined = "; ".join(str(v)[:60] for v in list(values)[:32])
+                prompts = [render_prompt(op, joined)]
+            else:
+                prompts = [render_prompt(op, v) for v in values]
+
+            rids = self._submit(prompts)
+            finished = self._collect(rids, t0)
+            raw = [finished[r].text for r in rids]
+
+            tok_in = sum(cost_mod.text_tokens(p) for p in prompts)
+            tok_out = sum(len(finished[r].output_ids or []) for r in rids)
+            if meter is not None:
+                # per-call latencies are the *measured* per-request SERVICE
+                # times (slot insert -> done) from the continuous batcher;
+                # the event scheduler re-queues jobs itself, so sojourn
+                # time (submit -> done) would double-count the slot-queue
+                # wait
+                per_call = [max(0.0, finished[r].done_s
+                                - (finished[r].started_s
+                                   or finished[r].submitted_s))
+                            for r in rids]
+                meter.record(self.tier.name, bk.Usage(
+                    calls=len(prompts), tok_in=tok_in, tok_out=tok_out,
+                    usd=self.tier.usd(tok_in, tok_out),
+                    latency_s=sum(per_call)),
+                    per_call_latency_s=per_call, op_kind=op.kind)
+
+            if self.oracle is not None:
+                if op.kind == plan_ir.REDUCE:
+                    return [self.oracle.answer_reduce(op, values)]
+                return [self.oracle.answer(op, v) for v in values]
+            return self._parse(op, raw, values)
 
     def _parse(self, op: plan_ir.Operator, raw: List[str],
                values: Sequence[Any]) -> List[Any]:

@@ -133,3 +133,154 @@ def test_jax_backend_through_executor(served):
     assert meter.calls("m1") == 3
     assert meter.total.latency_s > 0
     assert res.table is not None
+
+
+def test_engine_counters_match_the_requests(served):
+    """Three requests that all fit the slots at once: one token each at
+    insert, then one per tick while live, so the counters follow from
+    the requests' own lengths."""
+    _, bundle, params = served
+    eng = GenerationEngine(bundle, params, max_len=96, n_slots=4)
+    cb = ContinuousBatcher(eng)
+    for i, n in enumerate((3, 5, 8)):
+        cb.submit(f"counted request {i}", max_new_tokens=n)
+    reqs = list(cb.run().values())
+    outs = [len(r.output_ids) for r in reqs]
+    prompts = [len(r.prompt_ids) for r in reqs]
+    assert eng.stats["prefills"] == 3
+    assert eng.stats["prompt_tokens"] == sum(prompts)
+    # a request's tokens: the first at insert, then one per live tick
+    assert eng.stats["prefills"] + eng.stats["live_slot_ticks"] == sum(outs)
+    assert eng.stats["ticks"] == max(outs) - 1
+    assert eng.stats["live_slot_ticks"] == sum(m - 1 for m in outs)
+    # a live slot attends over its prompt and every output token so far,
+    # the one it writes included
+    assert eng.stats["context_tokens"] == sum(
+        p + k for p, m in zip(prompts, outs) for k in range(1, m))
+    assert eng.occupancy == pytest.approx(
+        sum(m - 1 for m in outs) / ((max(outs) - 1) * 4))
+
+
+def test_jax_backend_counters_under_concurrent_callers(served):
+    """More callers than cores and a short switch interval: every call
+    is counted once, and the lock waits lie inside the calls' time."""
+    import sys
+    import threading
+    from repro.core import plan as P
+    from repro.core.cost import DEFAULT_TIERS
+    from repro.engine import JAXBackend
+    _, bundle, params = served
+    eng = GenerationEngine(bundle, params, max_len=128, n_slots=4)
+    be = JAXBackend(DEFAULT_TIERS["m1"], eng, max_new_tokens=3)
+    op = P.Operator(P.FILTER, "Is it big?", "col")
+    n_threads, per_thread = 12, 2
+    errors = []
+
+    def caller(k):
+        try:
+            for j in range(per_thread):
+                assert len(be.run_values(op, [f"row {k}.{j}"])) == 1
+        except Exception as e:                    # reported below
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=caller, args=(k,))
+                   for k in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=300)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors, errors
+    s = be.stats
+    assert s["calls"] == n_threads * per_thread
+    assert 0 < s["lock_wait_s"] <= s["call_s"]
+    assert eng.stats["prefills"] == n_threads * per_thread
+    assert eng.stats["ticks"] > 0
+
+
+def test_programs_are_named(served):
+    """The two programs lower to modules named for what they do, so a
+    device trace tells prefill from decode."""
+    from repro.engine.engine import jitted_steps
+    _, bundle, params = served
+    decode, prefill = jitted_steps(bundle, max_len=32, dtype=jnp.float32)
+    cache = bundle.init_cache(2, 32, dtype=jnp.float32, per_slot_pos=True)
+    token = jnp.zeros((2, 1), jnp.int32)
+    batch = {"tokens": jnp.zeros((1, 16), jnp.int32),
+             "last_index": jnp.zeros((1,), jnp.int32)}
+    assert "module @jit_engine_decode" in decode.lower(
+        params, cache, token).as_text()
+    assert "module @jit_engine_prefill" in prefill.lower(
+        params, batch).as_text()
+
+
+def _profiled_spans(tmp_path, work):
+    """The ``engine.*`` host spans a profiler trace of ``work()`` holds:
+    [(name, start_ns, end_ns, {arg: value})], by start."""
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        work()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                   "*", "*.xplane.pb"))
+    spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+              {k: v for k, v in e.stats})
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith("engine.")]
+    return sorted(spans, key=lambda s: s[1])
+
+
+def _inside(child, parents):
+    return any(p[1] <= child[1] and child[2] <= p[2] for p in parents)
+
+
+def test_engine_spans_nest_and_count_the_work(served, tmp_path):
+    """Each insert and tick is a span with one child per phase, a call
+    holds its inserts and ticks, and the spans count what the counters
+    count. A lone caller never waits for the lock."""
+    from repro.core import plan as P
+    from repro.core.cost import DEFAULT_TIERS
+    from repro.engine import JAXBackend
+    _, bundle, params = served
+    eng = GenerationEngine(bundle, params, max_len=128, n_slots=2)
+    be = JAXBackend(DEFAULT_TIERS["m1"], eng, max_new_tokens=4)
+    op = P.Operator(P.FILTER, "Is it big?", "col")
+    spans = _profiled_spans(
+        tmp_path, lambda: be.run_values(op, ["tiny", "huge", "medium"]))
+    by = {}
+    for s in spans:
+        by.setdefault(s[0], []).append(s)
+
+    assert len(by["engine.insert"]) == eng.stats["prefills"] == 3
+    assert len(by["engine.tick"]) == eng.stats["ticks"]
+    assert sum(s[3]["live"] for s in by["engine.tick"]) == \
+        eng.stats["live_slot_ticks"]
+    assert sorted(s[3]["rid"] for s in by["engine.insert"]) == [0, 1, 2]
+    assert {s[3]["slot"] for s in by["engine.insert"]} == {0, 1}
+    assert all(s[3]["prompt_len"] > 0 for s in by["engine.insert"])
+    for child in ("engine.prefill", "engine.splice", "engine.first_token"):
+        assert len(by[child]) == 3
+        assert all(_inside(s, by["engine.insert"]) for s in by[child])
+    for child in ("engine.decode", "engine.tick_sync", "engine.tick_update"):
+        assert len(by[child]) == eng.stats["ticks"]
+        assert all(_inside(s, by["engine.tick"]) for s in by[child])
+    call, = by["engine.call"]
+    assert call[3] == {"kind": "filter", "rows": 3}
+    assert all(_inside(s, [call])
+               for s in by["engine.tick"] + by["engine.insert"])
+    assert be.stats["calls"] == 1
+    assert be.stats["lock_wait_s"] == 0.0
