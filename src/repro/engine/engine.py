@@ -240,11 +240,12 @@ class ContinuousBatcher:
 
     def step(self, key=None) -> bool:
         """One scheduling round: fill free slots from the queue, then one
-        decode tick. Returns True while work remains. This is the unit a
-        cooperating driver thread executes under a lock — callers that
-        share the batcher (e.g. ``JAXBackend`` under the threaded
-        execution driver) alternate steps so their requests batch together
-        on the engine's slots. ``key`` seeds THIS tick's sampling only;
+        decode tick. Returns True while work remains. This is the unit
+        that ``JAXBackend``'s one driver thread runs under the backend's
+        lock for every caller that shares the batcher: requests the
+        callers submitted since the last step enter free slots here, so
+        they batch together on the engine's slots. ``key`` seeds THIS
+        tick's sampling only;
         a caller looping step() with temperature>0 requests must split a
         fresh subkey per call (as ``run`` does) or every tick reuses the
         same noise."""

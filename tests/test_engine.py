@@ -203,6 +203,171 @@ def test_jax_backend_counters_under_concurrent_callers(served):
     assert eng.stats["ticks"] > 0
 
 
+class KeptEngine(GenerationEngine):
+    """``GenerationEngine`` that keeps every finished request."""
+
+    def __init__(self, *a, **kw):
+        super().__init__(*a, **kw)
+        self.done = []
+
+    def insert(self, req, slot):
+        done = super().insert(req, slot)
+        if done is not None:
+            self.done.append(done)
+        return done
+
+    def decode_tick(self, key=None):
+        done = super().decode_tick(key)
+        self.done.extend(done)
+        return done
+
+
+def _run_callers(n, target, timeout=120):
+    """``target(k)`` in ``n`` threads at once, k = 0..n-1: the errors
+    they raised."""
+    import threading
+    errors, start = [], threading.Barrier(n)
+
+    def caller(k):
+        try:
+            start.wait(timeout)
+            target(k)
+        except Exception as e:                    # reported by the caller
+            errors.append(e)
+
+    threads = [threading.Thread(target=caller, args=(k,)) for k in range(n)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=timeout)
+    assert not any(t.is_alive() for t in threads)
+    return errors
+
+
+def _await_waiters(lock, n, timeout=60):
+    """Wait until ``n`` threads wait on the ``FairLock`` ``lock``."""
+    import time
+    deadline = time.perf_counter() + timeout
+    while len(lock._waiters) < n:
+        assert time.perf_counter() < deadline
+        time.sleep(0.001)
+
+
+def test_fair_lock_hands_over_to_the_oldest_waiter():
+    """A holder that releases and takes the lock again at once queues
+    behind the threads already waiting, which get it in arrival order."""
+    import threading
+    from repro.engine.jax_backend import FairLock
+    lock, order = FairLock(), []
+
+    def waiter(k):
+        with lock:
+            order.append(k)
+
+    lock.acquire()
+    threads = []
+    for k in range(3):
+        threads.append(threading.Thread(target=waiter, args=(k,)))
+        threads[-1].start()
+        _await_waiters(lock, k + 1)
+    lock.release()
+    assert not lock.acquire(False)          # handed to waiter 0
+    lock.acquire()
+    order.append("holder")
+    lock.release()
+    for t in threads:
+        t.join(timeout=30)
+    assert not any(t.is_alive() for t in threads)
+    assert order == [0, 1, 2, "holder"]
+    assert lock.acquire(False)
+    lock.release()
+
+
+def test_one_driver_steps_every_callers_requests(served):
+    """Eight callers of one-row calls on 16 slots: each tick carries
+    about eight live slots and each step serves about eight calls, and
+    every request's tokens are those it gets sent alone."""
+    from repro.core import plan as P
+    from repro.core.cost import DEFAULT_TIERS
+    from repro.engine import JAXBackend
+    _, bundle, params = served
+    eng = KeptEngine(bundle, params, max_len=96, n_slots=16)
+    be = JAXBackend(DEFAULT_TIERS["m1"], eng, max_new_tokens=8)
+    op = P.Operator(P.MAP, "Name it.", "col", output_column="name")
+    n_threads, per_thread = 8, 3
+    rows = [f"row {k}.{j}" for k in range(n_threads)
+            for j in range(per_thread)]
+    errors = _run_callers(n_threads, lambda k: [
+        be.run_values(op, [rows[k * per_thread + j]])
+        for j in range(per_thread)])
+    assert not errors, errors
+    assert be.stats["calls"] == len(rows)
+    assert eng.stats["live_slot_ticks"] / eng.stats["ticks"] >= 4
+    assert be.stats["step_calls"] / be.stats["steps"] >= 4
+    together = {r.prompt: r.output_ids for r in eng.done}
+    alone = KeptEngine(bundle, params, max_len=96, n_slots=16)
+    lone = JAXBackend(DEFAULT_TIERS["m1"], alone, max_new_tokens=8)
+    for row in rows:
+        lone.run_values(op, [row])
+    assert len(together) == len(alone.done) == len(rows)
+    assert {r.prompt: r.output_ids for r in alone.done} == together
+    assert lone.stats["step_calls"] == lone.stats["steps"]
+    assert lone.stats["lock_wait_s"] == 0.0
+    # a call with no rows needs no step
+    steps = lone.stats["steps"]
+    assert lone.run_values(op, []) == []
+    assert lone.stats["steps"] == steps
+    assert lone.stats["calls"] == len(rows) + 1
+
+
+def test_a_failed_step_reaches_every_waiting_caller(served):
+    """A decode tick that raises once: each caller whose request was in
+    the batcher gets the error, no caller hangs, and the backend serves
+    the next call as a fresh one would."""
+    import threading
+    from repro.core import plan as P
+    from repro.core.cost import DEFAULT_TIERS
+    from repro.engine import JAXBackend
+
+    class Boom(RuntimeError):
+        pass
+
+    class FailingEngine(GenerationEngine):
+        failed = False
+
+        def decode_tick(self, key=None):
+            if not self.failed:
+                self.failed = True
+                raise Boom("tick failed")
+            return super().decode_tick(key)
+
+    _, bundle, params = served
+    eng = FailingEngine(bundle, params, max_len=96, n_slots=4)
+    be = JAXBackend(DEFAULT_TIERS["m1"], eng, max_new_tokens=4)
+    op = P.Operator(P.MAP, "Name it.", "col", output_column="name")
+    n_threads = 3
+    # hold the lock until every caller waits on it, so that all three
+    # submit before the driver's first step
+    be._lock.acquire()
+    got = []
+    runner = threading.Thread(target=lambda: got.extend(_run_callers(
+        n_threads, lambda k: be.run_values(op, [f"row {k}"]))))
+    runner.start()
+    _await_waiters(be._lock, n_threads)
+    be._lock.release()
+    runner.join(timeout=120)
+    assert not runner.is_alive()
+    assert len(got) == n_threads and all(isinstance(e, Boom) for e in got)
+    assert be.stats["calls"] == 0 and not eng.active.any()
+
+    after = be.run_values(op, ["row 0"])
+    fresh = JAXBackend(DEFAULT_TIERS["m1"],
+                       GenerationEngine(bundle, params, max_len=96,
+                                        n_slots=4), max_new_tokens=4)
+    assert after == fresh.run_values(op, ["row 0"])
+    assert be.stats["calls"] == 1
+
+
 def test_programs_are_named(served):
     """The two programs lower to modules named for what they do, so a
     device trace tells prefill from decode."""
