@@ -5,11 +5,11 @@ cell.
         --seconds <s> [--keep <trace.json.gz>]
 
 from the root of a checkout runs the cell as ``chipbench/run.py ...
---trace 1`` does, with two additions: the loaded trace keeps the
+--trace 1`` does, and reads the run's record: its trace, which holds the
 program's host spans (``engine.*``) beside the benchmark's (``bench.*``),
-and ``JAXBackend.stats`` and ``GenerationEngine.stats`` are copied, under
-the backend's lock, at the window's open and at its close. The last line
-of standard output is one JSON object: the run's result, as ``run.py``
+and its copies of ``JAXBackend.stats`` and ``GenerationEngine.stats``
+from the window's open and close (``Run.counters``). The last line of
+standard output is one JSON object: the run's result, as ``run.py``
 prints it, under ``result``, and under ``engine``:
 
 - ``lock_wait_share``: per cent of the engine tier's call seconds that
@@ -34,12 +34,10 @@ tick or insert in the traced part.
 from __future__ import annotations
 
 import argparse
-import contextlib
 import json
 import pathlib
 import sys
-import time
-from typing import List, Optional
+from typing import Optional
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 for _p in (str(ROOT / "src"), str(ROOT)):
@@ -48,8 +46,8 @@ for _p in (str(ROOT / "src"), str(ROOT)):
 
 from chipbench import harness, registry  # noqa: E402
 from chipbench import trace as tr  # noqa: E402
+from chipbench.measures import counter_growth  # noqa: E402
 
-PROGRAM_SPAN_PREFIX = "engine."
 # each span kind that parents others, and its children; the eager ops of
 # a tick between ``engine.decode`` and ``engine.tick_sync`` are the
 # tick's own time
@@ -82,67 +80,11 @@ def span_count(trace: tr.Trace, window: tr.Interval, name: str) -> int:
                if n == name and window[0] <= e < window[1])
 
 
-@contextlib.contextmanager
-def program_spans():
-    """Within: ``trace.load_xplane`` keeps the ``engine.*`` host spans
-    beside the benchmark's."""
-    old = tr.SPAN_PREFIX
-    tr.SPAN_PREFIX = (old, PROGRAM_SPAN_PREFIX)
-    try:
-        yield
-    finally:
-        tr.SPAN_PREFIX = old
-
-
-@contextlib.contextmanager
-def counting(snaps: List[tuple]):
-    """Within: each closed-loop ``harness.run_cell`` appends to ``snaps``
-    ``(clock, JAXBackend.stats, GenerationEngine.stats)``, copied under
-    the backend's lock, at its window's open and at its close. The
-    recording engine starts recording just before the first copy, so its
-    records and the counters start from one instant. A backend without
-    ``stats`` gives an empty dict."""
-    backends = []
-    real_build, real_loop = harness.sysm.build_context, harness.closed_loop
-
-    def snapshot(engine):
-        backend = backends[-1]
-        with backend._lock:
-            return (time.perf_counter(),
-                    dict(getattr(backend, "stats", {})), dict(engine.stats))
-
-    def build_context(*a, **kw):
-        out = real_build(*a, **kw)
-        backends.append(out[1])
-        return out
-
-    def closed_loop(server, mix, table, engine, *rest):
-        *rest, at_open = rest
-
-        def open_window():
-            engine.recording = True
-            snaps.append(snapshot(engine))
-            at_open()
-
-        out = real_loop(server, mix, table, engine, *rest, open_window)
-        snaps.append(snapshot(engine))
-        return out
-
-    harness.sysm.build_context = build_context
-    harness.closed_loop = closed_loop
-    try:
-        yield
-    finally:
-        harness.sysm.build_context = real_build
-        harness.closed_loop = real_loop
-
-
-def lock_wait_share(snaps) -> Optional[float]:
-    (_, b0, _), (_, b1, _) = snaps
-    if "call_s" not in b1 or b1["call_s"] <= b0["call_s"]:
+def lock_wait_share(run) -> Optional[float]:
+    call_s = counter_growth(run, "backend", "call_s")
+    if call_s is None or call_s <= 0:
         return None
-    return (100.0 * (b1["lock_wait_s"] - b0["lock_wait_s"])
-            / (b1["call_s"] - b0["call_s"]))
+    return 100.0 * counter_growth(run, "backend", "lock_wait_s") / call_s
 
 
 def rows_per_s(run, lo: float, hi: float) -> Optional[float]:
@@ -154,8 +96,8 @@ def rows_per_s(run, lo: float, hi: float) -> Optional[float]:
                if lo <= t1 < hi) / (hi - lo)
 
 
-def readings(run, snaps) -> dict:
-    out = {"lock_wait_share": lock_wait_share(snaps) if snaps else None}
+def readings(run) -> dict:
+    out = {"lock_wait_share": lock_wait_share(run)}
     # the traced part is the window's last TRACE_S seconds; on the host's
     # clock, which the trace's own clock is not
     w = run.window
@@ -210,15 +152,12 @@ def main(argv=None, root: pathlib.Path = ROOT, require_chip: bool = True,
         enable_compile_cache()
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    snaps: List[tuple] = []
     runs: list = []
-    with program_spans(), counting(snaps):
-        result = harness.run_cell(pathlib.Path(root), args.workload,
-                                  args.seed, args.seconds, True,
-                                  t_start=t_start, keep_trace=args.keep,
-                                  on_run=runs.append)
-    print(json.dumps({"result": result,
-                      "engine": readings(runs[0], snaps)}), flush=True)
+    result = harness.run_cell(pathlib.Path(root), args.workload, args.seed,
+                              args.seconds, True, t_start=t_start,
+                              keep_trace=args.keep, on_run=runs.append)
+    print(json.dumps({"result": result, "engine": readings(runs[0])}),
+          flush=True)
     return 0
 
 

@@ -5,6 +5,8 @@ warms up every shape the mix's traffic uses, drives the window (open
 loop: queries due on a seeded schedule; closed loop: clients that each
 wait for their answer), then checks what the window produced against the
 plain references, and hands a :class:`Run` record to the metric readers.
+Everything that depends on the model's architecture comes from the
+configuration's architecture module (``registry.arch``).
 """
 from __future__ import annotations
 
@@ -26,7 +28,6 @@ import numpy as np
 from chipbench import registry, semref, traffic
 from chipbench import system as sysm
 from chipbench import trace as tr
-from chipbench import weights as wts
 
 REF_SAMPLE = 32          # engine requests the reference re-runs
 # The traced run traces the window's last TRACE_S seconds: a trace of all
@@ -55,7 +56,8 @@ class Run:
     workload: str
     cfg: dict
     mix: dict
-    dims: wts.Dims
+    arch: Any                            # chipbench/archs/<model_type>.py
+    dims: Any                            # arch.dims(cfg)
     seed: int
     seconds: float
     window: Tuple[float, float]          # host clock
@@ -65,6 +67,10 @@ class Run:
     backend: Any                         # RecordingJAXBackend
     embed: Any                           # RecordingEmbeddingBackend | None
     device: dict
+    # the program's counters at the window's open and close:
+    # {"open"|"close": {"t": host clock, "backend": JAXBackend.stats,
+    #                   "engine": GenerationEngine.stats}}
+    counters: Optional[Dict[str, dict]] = None
     peaks: Optional[dict] = None
     trace: Optional[tr.Trace] = None
     trace_window: Optional[Tuple[float, float]] = None
@@ -266,9 +272,9 @@ def closed_loop(server, mix, table, engine, seconds: float, seed: int,
     deadline = t0 + float(mix.get("warmup_s", 5.0))
     while time.perf_counter() < deadline and not engine.active.all():
         time.sleep(0.01)
+    engine.recording = True
     at_open()
     t_open = time.perf_counter()
-    engine.recording = True
     with contextlib.ExitStack() as stack:
         traced_part(t_open, seconds, traced_span, stack)
         _sleep_until(t_open + seconds)
@@ -372,7 +378,8 @@ def run_cell(root: pathlib.Path, workload: str, seed: int, seconds: float,
     cell = registry.cell(bench, workload)
     cfg = registry.config(bench, root, cell["config"])
     mix = registry.mix([root], cell["traffic"])
-    dims = wts.Dims.from_config(cfg)
+    arch = registry.arch([root], cfg)
+    dims = arch.dims(cfg)
     dep = cfg["deployment"]
     compiles = CompileCounter()
 
@@ -385,7 +392,7 @@ def run_cell(root: pathlib.Path, workload: str, seed: int, seconds: float,
     if mix.get("max_rows"):
         table = table.head(int(mix["max_rows"]))
     phase("table")
-    engine = sysm.build_engine(cfg, seed)
+    engine = sysm.build_engine(arch, cfg, seed)
     jax.block_until_ready(engine.params)
     phase("weights")
     ctx, backend, embed = sysm.build_context(cfg, mix, engine, oracle)
@@ -420,10 +427,11 @@ def run_cell(root: pathlib.Path, workload: str, seed: int, seconds: float,
             tracing["on"] = False
             log(f"trace written in {time.perf_counter() - t0:.3f}s")
 
-    marks = {}
+    marks, snaps = {}, {}
 
     def at_open():
         marks["c0"] = compiles.n
+        snaps["open"] = counters(backend, engine)
 
     server = QueryServer(ctx)
     try:
@@ -434,6 +442,7 @@ def run_cell(root: pathlib.Path, workload: str, seed: int, seconds: float,
             at_open()
             recs, window, late = open_loop(server, mix, table, seconds, seed,
                                            traced_span)
+            snaps["close"] = counters(backend, engine)
             in_window_compiles = compiles.n - marks["c0"]
             stop_trace()
             settle_open(recs, window[1] + float(
@@ -443,6 +452,7 @@ def run_cell(root: pathlib.Path, workload: str, seed: int, seconds: float,
             recs, window, extra = closed_loop(
                 server, mix, table, engine, seconds, seed, traced_span,
                 at_open)
+            snaps["close"] = counters(backend, engine)
             in_window_compiles = compiles.n - marks["c0"]
             extra["stop"].set()
             backend.stop.set()
@@ -489,8 +499,9 @@ def run_cell(root: pathlib.Path, workload: str, seed: int, seconds: float,
     gaps = None
     if requests:
         t0 = time.perf_counter()
-        gaps = reference.served_gaps(dims, seed, requests, length=length,
-                                     n_out=n_out, control=control)
+        gaps = reference.served_gaps(arch, dims, seed, requests,
+                                     length=length, n_out=n_out,
+                                     control=control)
         log(f"reference: {len(requests)} requests, {len(gaps)} served "
             f"tokens{' (fp8 control)' if control else ''}, "
             f"{int((gaps > 0).sum())} not the reference's best, "
@@ -513,9 +524,9 @@ def run_cell(root: pathlib.Path, workload: str, seed: int, seconds: float,
     checks["foreign_llm_calls"] = {"value": float(foreign), "limit": 0.0}
     correct = all(c["value"] <= c["limit"] for c in checks.values())
 
-    run = Run(workload, cfg, mix, dims, seed, seconds, window, setup_s,
-              recs, engine, backend, embed, device, trace=tr_obj,
-              trace_window=tr_window)
+    run = Run(workload, cfg, mix, arch, dims, seed, seconds, window,
+              setup_s, recs, engine, backend, embed, device,
+              counters=snaps, trace=tr_obj, trace_window=tr_window)
     if device["platform"] == "tpu":
         from chipbench import peaks
         run.peaks = peaks.peaks(device["kind"])
@@ -545,6 +556,14 @@ def run_cell(root: pathlib.Path, workload: str, seed: int, seconds: float,
         log(f"check {name} {c['value']!r} limit {c['limit']!r} "
             f"{'ok' if c['value'] <= c['limit'] else 'FAIL'}")
     return result
+
+
+def counters(backend, engine) -> dict:
+    """Copies of the program's counters, taken under the backend's lock,
+    which every write to them holds, so that no step is half counted."""
+    with backend._lock:
+        return {"t": time.perf_counter(), "backend": dict(backend.stats),
+                "engine": dict(engine.stats)}
 
 
 def foreign_calls(ctx, backend) -> int:

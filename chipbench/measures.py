@@ -1,7 +1,6 @@
 """Quantities several metric readers share, each from one source."""
 from __future__ import annotations
 
-from chipbench import flops as fl
 from chipbench import trace as tr
 
 
@@ -16,11 +15,26 @@ def window_ticks(run):
 
 def engine_flops(run) -> float:
     """Operations the engine's work in the window needs (program_counter:
-    the engine's own prefill and tick records)."""
-    total = sum(fl.prefill_flops(run.dims, n) for n in window_prefills(run))
-    total += sum(fl.decode_flops(run.dims, live, ctx)
+    the engine's own prefill and tick records), as the configuration's
+    architecture counts them."""
+    arch, dims = run.arch, run.dims
+    total = sum(arch.prefill_flops(dims, n) for n in window_prefills(run))
+    total += sum(arch.decode_flops(dims, live, ctx)
                  for live, ctx in window_ticks(run) if live)
     return float(total)
+
+
+def counter_growth(run, part: str, key: str):
+    """Growth over the window of the program's counter ``key`` in
+    ``part`` (``backend``: ``JAXBackend.stats``, ``engine``:
+    ``GenerationEngine.stats``); None where the run holds no such
+    counter."""
+    if run.counters is None:
+        return None
+    opened, closed = (run.counters[k][part] for k in ("open", "close"))
+    if key not in closed:
+        return None
+    return closed[key] - opened[key]
 
 
 def idle_share(run):

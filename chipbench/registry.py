@@ -1,17 +1,29 @@
 """Finds a cell's parts by name: its configuration and traffic mix (data
-files) and the reader of each metric (one small module each).
+files), its configuration's architecture, and the reader of each metric
+(one small module each).
 
-A name maps to a file without code: ``chipbench/mixes/<mix>.json`` and
-``chipbench/metrics/<metric>.py`` with each ``.`` of the metric's name
-read as ``_`` (``mfu.batch`` -> ``mfu_batch.py``). Each is looked up
-under the checkout's root first and then beside this module, so a new
-cell, mix or metric is a new file and an entry in ``BENCHMARK.json``.
+A name maps to a file without code: ``chipbench/mixes/<mix>.json``,
+``chipbench/archs/<model_type>.py`` for the configuration file's
+``model_type``, and ``chipbench/metrics/<metric>.py`` with each ``.`` of
+the metric's name read as ``_`` (``mfu.batch`` -> ``mfu_batch.py``).
+Each is looked up under the checkout's root first and then beside this
+module, so a new cell, mix, architecture or metric is a new file and an
+entry in ``BENCHMARK.json``.
+
+An architecture module provides ``dims(cfg)`` (its frozen sizes, with
+``layers``), ``layout(dims)`` (its weight leaves, ``weights.Leaf``),
+``program_config(cfg)`` (the program's ``ModelConfig``, every size from
+the file), its plain reference ``hidden(dims, seed, tokens, fp8)`` and
+``logits_at(dims, seed, x, at, fp8)`` (``reference.served_gaps`` calls
+them), and ``prefill_flops(dims, n)`` and ``decode_flops(dims, live,
+ctx_sum)``.
 """
 from __future__ import annotations
 
 import importlib.util
 import json
 import pathlib
+import sys
 from typing import Iterable, List
 
 HERE = pathlib.Path(__file__).resolve().parent
@@ -57,14 +69,32 @@ def metric_module_name(metric: str) -> str:
     return metric.replace(".", "_")
 
 
+def _load(path: pathlib.Path, name: str):
+    """The module at ``path``, imported as ``name`` (once per path: kept
+    in ``sys.modules``, where dataclasses look their module up)."""
+    mod = sys.modules.get(name)
+    if mod is not None and mod.__file__ == str(path):
+        return mod
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def metric_reader(roots, metric: str):
     """The ``read(run)`` function of the metric's reader module."""
-    path = _find(roots, "metrics", f"{metric_module_name(metric)}.py")
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{metric_module_name(metric)}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    name = metric_module_name(metric)
+    return _load(_find(roots, "metrics", f"{name}.py"),
+                 f"chipbench_metric_{name}").read
+
+
+def arch(roots, cfg: dict):
+    """The module of the configuration's architecture, found by its
+    ``model_type``."""
+    model_type = cfg["model_type"]
+    return _load(_find(roots, "archs", f"{model_type}.py"),
+                 f"chipbench_arch_{model_type}")
 
 
 def metrics_for(bench: dict, section: str, workload: str) -> List[dict]:

@@ -19,7 +19,6 @@ import jax
 
 from chipbench import weights as wts
 
-from repro.configs import FAMILY_DENSE, ModelConfig
 from repro.core import backends as bk
 from repro.core import cascade as casc_mod
 from repro.core import plan as plan_ir
@@ -37,29 +36,20 @@ class WindowStopped(RuntimeError):
     closed in a cell whose queries outlast it: it stops them cleanly."""
 
 
-def program_config(cfg: dict) -> ModelConfig:
-    """The program's model configuration, as the configuration file
-    states it (every size from the file, none from the program's zoo)."""
-    dims = wts.Dims.from_config(cfg)
-    return ModelConfig(
-        name=cfg["name"], family=FAMILY_DENSE, n_layers=dims.layers,
-        d_model=dims.d_model, n_heads=dims.heads, n_kv_heads=dims.kv_heads,
-        head_dim=dims.head_dim, d_ff=dims.d_ff, vocab_size=dims.vocab,
-        qkv_bias=True, rope_theta=dims.rope_theta, rms_eps=dims.rms_eps,
-        tie_embeddings=dims.tied)
-
-
 def served_dtype(cfg: dict):
     return wts.DTYPES[cfg["torch_dtype"]]
 
 
-def program_params(cfg: dict, seed: int):
-    """The served weights: the benchmark's seeded weights in the program's
-    parameter tree, made by one jitted call in the served type."""
-    dims = wts.Dims.from_config(cfg)
-    bundle = registry.build(program_config(cfg))
+def program_params(arch, cfg: dict, seed: int):
+    """The served weights: the benchmark's seeded weights, in the layout
+    of the configuration's architecture module ``arch``, put in the
+    program's parameter tree; made by one jitted call in the served
+    type."""
+    dims = arch.dims(cfg)
+    bundle = registry.build(arch.program_config(cfg))
     shapes = jax.eval_shape(bundle.init, jax.random.PRNGKey(0))
-    flat = wts.make_all(dims, seed, served_dtype(cfg))
+    flat = wts.make_all(arch.layout(dims), dims.layers, seed,
+                        served_dtype(cfg))
     return bundle, wts.to_program_tree(shapes, flat)
 
 
@@ -172,9 +162,9 @@ def build_context(cfg: dict, mix: dict, engine: GenerationEngine, oracle):
     return ctx, backend, embed
 
 
-def build_engine(cfg: dict, seed: int) -> RecordingEngine:
+def build_engine(arch, cfg: dict, seed: int) -> RecordingEngine:
     dep = cfg["deployment"]
-    bundle, params = program_params(cfg, seed)
+    bundle, params = program_params(arch, cfg, seed)
     return RecordingEngine(bundle, params, max_len=int(dep["max_len"]),
                            n_slots=int(dep["slots"]),
                            dtype=served_dtype(cfg))

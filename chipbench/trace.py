@@ -3,10 +3,11 @@ times.
 
 ``load_xplane`` reads the ``.xplane.pb`` that ``jax.profiler`` writes and
 keeps two lists on one clock: the device's operations (the ``XLA Ops``
-line of each TPU plane) and the benchmark's own host spans (events named
-``bench.*``, written by ``jax.profiler.TraceAnnotation``). Everything
-else reduces those lists, so the reduction can be checked on a small
-recorded trace without a chip.
+line of each TPU plane) and the host spans of the benchmark
+(``bench.*``) and of the program (``engine.*``), each written by
+``jax.profiler.TraceAnnotation``. Everything else reduces those lists,
+so the reduction can be checked on a small recorded trace without a
+chip.
 """
 from __future__ import annotations
 
@@ -20,7 +21,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 Interval = Tuple[float, float]           # (start_s, end_s)
 
-SPAN_PREFIX = "bench."
+SPAN_PREFIXES = ("bench.", "engine.")
 WINDOW_SPAN = "bench.window"
 # what the host was doing, most specific first: an idle gap goes to the
 # first of these whose span covers most of it
@@ -33,7 +34,8 @@ class Trace:
     # per device: [(name, start_s, end_s, program)], sorted by start;
     # program is the XLA module (jitted program) the operation ran in
     device_ops: Dict[str, List[Tuple[str, float, float, str]]]
-    # [(name, start_s, end_s)] of the benchmark's host spans
+    # [(name, start_s, end_s)] of the benchmark's and the program's host
+    # spans
     spans: List[Tuple[str, float, float]]
 
     def window(self) -> Optional[Interval]:
@@ -93,7 +95,7 @@ def load_xplane(path: str) -> Trace:
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for e in line.events:
-                    if e.name.startswith(SPAN_PREFIX):
+                    if e.name.startswith(SPAN_PREFIXES):
                         spans.append((e.name, e.start_ns * 1e-9,
                                       (e.start_ns + e.duration_ns) * 1e-9))
     spans.sort(key=lambda x: x[1])

@@ -1,12 +1,13 @@
-"""Seeded random weights for the benchmark's dense GQA configurations.
+"""Seeded random weights for the benchmark's configurations.
 
 One function, :func:`make_leaf`, draws every weight from the seed by its
 name and layer. The served model gets all of them from one jitted call,
 in the type they are served in (:func:`make_all`); the plain reference
 draws one layer at a time (:func:`make_layer`) and gets the same numbers.
-Nothing here reads the program: the layout is the published one
-(Qwen2-style blocks with QKV bias), named by the paths under which the
-program's parameter tree keeps them.
+Nothing here reads the program or knows an architecture: the leaves are
+a layout that the configuration's architecture (``chipbench/archs/``)
+gives, named by the paths under which the program's parameter tree
+keeps them.
 """
 from __future__ import annotations
 
@@ -22,64 +23,13 @@ DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
 
 
 @dataclasses.dataclass(frozen=True)
-class Dims:
-    """The sizes one forward pass needs, read from a configuration file."""
-    layers: int
-    d_model: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab: int
-    rope_theta: float
-    rms_eps: float
-    tied: bool
-
-    @classmethod
-    def from_config(cls, cfg: dict) -> "Dims":
-        heads = int(cfg["num_attention_heads"])
-        d = int(cfg["hidden_size"])
-        return cls(layers=int(cfg["num_hidden_layers"]), d_model=d,
-                   heads=heads, kv_heads=int(cfg["num_key_value_heads"]),
-                   head_dim=int(cfg.get("head_dim", d // heads)),
-                   d_ff=int(cfg["intermediate_size"]),
-                   vocab=int(cfg["vocab_size"]),
-                   rope_theta=float(cfg["rope_theta"]),
-                   rms_eps=float(cfg["rms_norm_eps"]),
-                   tied=bool(cfg["tie_word_embeddings"]))
-
-
-@dataclasses.dataclass(frozen=True)
 class Leaf:
     name: str             # program path, "/"-joined
     shape: Tuple[int, ...]  # one layer's shape for per-layer leaves
     per_layer: bool
     kind: str             # "norm" | "bias" | "embed" | "dense"
     fan_in: int = 1
-
-
-def layout(dims: Dims) -> List[Leaf]:
-    d, h, kv, hd, f = (dims.d_model, dims.heads, dims.kv_heads,
-                       dims.head_dim, dims.d_ff)
-    leaves = [
-        Leaf("embed/embedding", (dims.vocab, d), False, "embed", d),
-        Leaf("final_norm/scale", (d,), False, "norm"),
-        Leaf("layers/attn_norm/scale", (d,), True, "norm"),
-        Leaf("layers/attn/q/w", (d, h, hd), True, "dense", d),
-        Leaf("layers/attn/q/b", (h, hd), True, "bias"),
-        Leaf("layers/attn/k/w", (d, kv, hd), True, "dense", d),
-        Leaf("layers/attn/k/b", (kv, hd), True, "bias"),
-        Leaf("layers/attn/v/w", (d, kv, hd), True, "dense", d),
-        Leaf("layers/attn/v/b", (kv, hd), True, "bias"),
-        Leaf("layers/attn/o/w", (h, hd, d), True, "dense", h * hd),
-        Leaf("layers/ffn_norm/scale", (d,), True, "norm"),
-        Leaf("layers/ffn/gate/w", (d, f), True, "dense", d),
-        Leaf("layers/ffn/up/w", (d, f), True, "dense", d),
-        Leaf("layers/ffn/down/w", (f, d), True, "dense", f),
-    ]
-    if not dims.tied:
-        leaves.append(Leaf("unembed/w", (d, dims.vocab), False, "dense", d))
-    return leaves
+    depth: Optional[int] = None  # layers a per-layer leaf stacks; None: all
 
 
 def seed_words(seed: int) -> np.ndarray:
@@ -117,19 +67,20 @@ def make_leaf(seed: int, leaf: Leaf, layer: Optional[int], dtype):
     return _scaled(leaf, z, dtype)
 
 
-def make_all(dims: Dims, seed: int, dtype) -> Dict[str, jax.Array]:
-    """Every leaf, per-layer leaves stacked on a leading layer axis, made
-    on the device by one jitted program. The per-layer draws are vmapped
-    over the layers' keys, which gives the numbers :func:`make_leaf`
-    gives one layer at a time."""
-    leaves = layout(dims)
+def make_all(leaves: List[Leaf], layers: int, seed: int,
+             dtype) -> Dict[str, jax.Array]:
+    """Every leaf, per-layer leaves stacked on a leading layer axis of
+    their ``depth`` (``layers`` where it is None), made on the device by
+    one jitted program. The per-layer draws are vmapped over the layers'
+    keys, which gives the numbers :func:`make_leaf` gives one layer at a
+    time."""
 
     def build(words):
         out = {}
         for leaf in leaves:
             if leaf.per_layer:
                 keys = jnp.stack([_leaf_key(words, leaf.name, i)
-                                  for i in range(dims.layers)])
+                                  for i in range(leaf.depth or layers)])
             else:
                 keys = _leaf_key(words, leaf.name, None)[None]
             z = jax.vmap(lambda k, s=leaf.shape: jax.random.normal(
@@ -141,17 +92,27 @@ def make_all(dims: Dims, seed: int, dtype) -> Dict[str, jax.Array]:
     return jax.jit(build)(seed_words(seed))
 
 
-def make_layer(dims: Dims, seed: int, layer: int, dtype=jnp.float32):
-    """One layer's leaves, as :func:`make_all` holds them, by short name
-    (``attn/q/w`` ...), cast to ``dtype`` from the served type."""
+def make_layer(leaves: List[Leaf], seed: int, layer: int,
+               dtype=jnp.float32):
+    """Layer ``layer`` of the per-layer leaves that stack it, as
+    :func:`make_all` holds them, by the name under their stack (``attn/q/w``
+    for ``layers/attn/q/w`` ...), cast to ``dtype`` from the served
+    type. Where two stacks hold a layer, pass one stack's leaves."""
     served = DTYPES["bfloat16"]
-    return {leaf.name[len("layers/"):]:
-            make_leaf(seed, leaf, layer, served).astype(dtype)
-            for leaf in layout(dims) if leaf.per_layer}
+    out = {}
+    for leaf in leaves:
+        if leaf.per_layer and (leaf.depth is None or layer < leaf.depth):
+            short = leaf.name.split("/", 1)[1]
+            if short in out:
+                raise ValueError(f"two stacks hold {short!r} at layer "
+                                 f"{layer}")
+            out[short] = make_leaf(seed, leaf, layer, served).astype(dtype)
+    return out
 
 
-def make_global(dims: Dims, seed: int, name: str, dtype=jnp.float32):
-    leaf = next(x for x in layout(dims) if x.name == name)
+def make_global(leaves: List[Leaf], seed: int, name: str,
+                dtype=jnp.float32):
+    leaf = next(x for x in leaves if x.name == name)
     return make_leaf(seed, leaf, None, DTYPES["bfloat16"]).astype(dtype)
 
 

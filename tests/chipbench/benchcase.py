@@ -13,12 +13,20 @@ for p in (str(REPO), str(REPO / "src")):
 
 
 def tiny_root(tmp: pathlib.Path, movie_rows: int = 64,
-              game_rows: int = 200) -> pathlib.Path:
+              game_rows: int = 200, model_type: str = "") -> pathlib.Path:
     """A root whose BENCHMARK.json has the real metrics, each reported in
     both of two cells of the CPU-sized ``tiny`` configuration under
-    cut-down copies of the two mixes (open and closed loop)."""
+    cut-down copies of the two mixes (open and closed loop). With
+    ``model_type``, the root also holds ``chipbench/archs/<model_type>.py``,
+    a copy of the benchmark's qwen2 architecture, and ``tiny`` names it."""
     (tmp / "chipbench" / "mixes").mkdir(parents=True)
-    shutil.copy(DATA / "tiny.json", tmp / "tiny.json")
+    tiny = json.loads((DATA / "tiny.json").read_text())
+    if model_type:
+        (tmp / "chipbench" / "archs").mkdir()
+        shutil.copy(REPO / "chipbench" / "archs" / "qwen2.py",
+                    tmp / "chipbench" / "archs" / f"{model_type}.py")
+        tiny["model_type"] = model_type
+    (tmp / "tiny.json").write_text(json.dumps(tiny))
     movie = json.loads((REPO / "chipbench/mixes/movie-interactive.json")
                        .read_text())
     movie.update(name="tiny-movie", rate_qps=3.0, max_rows=movie_rows,

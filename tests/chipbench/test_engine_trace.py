@@ -121,7 +121,7 @@ def window_run(t):
     alone: no counters, no calls."""
     import types
     return types.SimpleNamespace(window=t.window(), trace=t,
-                                 trace_window=t.window(),
+                                 trace_window=t.window(), counters=None,
                                  backend=types.SimpleNamespace(calls=[]))
 
 
@@ -133,7 +133,7 @@ def test_readings_of_a_trace_without_the_engine_spans():
     from chipbench.engine_readings import readings
     t = tr.Trace.load(str(REPO / "chipbench" / "testdata"
                           / "trace_v5e_game_40ms.json.gz"))
-    got = readings(window_run(t), [])
+    got = readings(window_run(t))
     assert got["lock_wait_share"] is None
     assert got["ticks"] == got["inserts"] == 0
     for k in ("decode_ms_per_tick", "tick_idle_ms", "insert_idle_ms"):
@@ -147,7 +147,7 @@ def test_readings_of_the_engine_trace(engine_trace):
     """Per tick: the decode program's device time, and device idle that
     agrees with the benchmark's gap attribution; per insert likewise."""
     from chipbench.engine_readings import readings
-    got = readings(window_run(engine_trace), [])
+    got = readings(window_run(engine_trace))
     assert got["ticks"] == 5 and got["inserts"] == 1
     assert 6.0 < got["decode_ms_per_tick"] < 7.0
     for kind, n in (("tick", 5), ("insert", 1)):

@@ -4,7 +4,7 @@ import json
 
 import pytest
 
-from benchcase import DATA, run_tiny, tiny_root
+from benchcase import DATA, REPO, run_tiny, tiny_root
 
 
 @pytest.fixture
@@ -14,11 +14,18 @@ def tiny(tmp_path):
     return tiny_root(tmp_path)
 
 
-def _served(cfg, seed, dtype):
+def _tiny():
+    """The tiny configuration and its architecture module."""
+    from chipbench import registry
+    cfg = json.loads((DATA / "tiny.json").read_text())
+    return cfg, registry.arch([REPO], cfg)
+
+
+def _served(arch, cfg, seed, dtype):
     from chipbench import system as sysm
     from repro.engine.engine import ContinuousBatcher
     cfg = dict(cfg, torch_dtype=dtype)
-    engine = sysm.build_engine(cfg, seed)
+    engine = sysm.build_engine(arch, cfg, seed)
     b = ContinuousBatcher(engine)
     rids = [b.submit(f"Answer true or false. Row {i}: " + "x" * (7 * i),
                      max_new_tokens=4) for i in range(8)]
@@ -28,26 +35,26 @@ def _served(cfg, seed, dtype):
 
 @pytest.mark.parametrize("seed", [11, 2**33 + 7, 3_000_000_019])
 def test_fp8_control_fails_the_logit_limit(seed):
-    from chipbench import reference, weights
-    cfg = json.loads((DATA / "tiny.json").read_text())
-    dims = weights.Dims.from_config(cfg)
+    from chipbench import reference
+    cfg, arch = _tiny()
+    dims = arch.dims(cfg)
     limit = cfg["check"]["logit_gap"]
-    reqs = _served(cfg, seed, "bfloat16")
+    reqs = _served(arch, cfg, seed, "bfloat16")
     kw = dict(length=176, n_out=4)
-    program = reference.served_gaps(dims, seed, reqs, **kw).max()
-    control = reference.served_gaps(dims, seed, reqs, control=True,
+    program = reference.served_gaps(arch, dims, seed, reqs, **kw).max()
+    control = reference.served_gaps(arch, dims, seed, reqs, control=True,
                                     **kw).max()
     assert program <= limit < control, (program, limit, control)
 
 
 def test_float32_engine_matches_the_reference_exactly():
-    from chipbench import reference, weights
-    cfg = json.loads((DATA / "tiny.json").read_text())
-    dims = weights.Dims.from_config(cfg)
+    from chipbench import reference
+    cfg, arch = _tiny()
     import jax
     with jax.default_matmul_precision("highest"):
-        reqs = _served(cfg, 5, "float32")
-    gaps = reference.served_gaps(dims, 5, reqs, length=176, n_out=4)
+        reqs = _served(arch, cfg, 5, "float32")
+    gaps = reference.served_gaps(arch, arch.dims(cfg), 5, reqs, length=176,
+                                 n_out=4)
     assert gaps.max() == 0.0
 
 

@@ -6,15 +6,19 @@ import pytest
 from benchcase import REPO
 
 
-def dims(name):
-    from chipbench import weights
-    return weights.Dims.from_config(json.loads(
-        (REPO / "chipbench" / "configs" / f"{name}.json").read_text()))
+def qwen2(name):
+    """The configuration's architecture module (``archs/qwen2.py``) and
+    its dims."""
+    from chipbench import registry
+    cfg = json.loads(
+        (REPO / "chipbench" / "configs" / f"{name}.json").read_text())
+    arch = registry.arch([REPO], cfg)
+    assert arch.__file__.endswith("archs/qwen2.py")
+    return arch, arch.dims(cfg)
 
 
 def test_qwen2_weights_per_token():
-    d = dims("qwen2-0.5b")
-    from chipbench import flops
+    flops, d = qwen2("qwen2-0.5b")
     # per layer: q/k/v 896*(14+2+2)*64, o 14*64*896, SwiGLU 3*896*4864
     per_layer = 896 * 18 * 64 + 896 * 896 + 3 * 896 * 4864
     assert per_layer == 14_909_440
@@ -23,8 +27,7 @@ def test_qwen2_weights_per_token():
 
 
 def test_codeqwen_stage_weights_and_published_size():
-    d = dims("codeqwen1.5-7b-l16")
-    from chipbench import flops
+    flops, d = qwen2("codeqwen1.5-7b-l16")
     per_layer = 4096 * (32 + 8) * 128 + 4096 * 4096 + 3 * 4096 * 13440
     assert per_layer == 202_899_456
     assert flops.matmul_params(d) == 16 * per_layer
@@ -36,8 +39,7 @@ def test_codeqwen_stage_weights_and_published_size():
 
 
 def test_prefill_and_decode_by_hand():
-    from chipbench import flops
-    d = dims("qwen2-0.5b")
+    flops, d = qwen2("qwen2-0.5b")
     m, h = flops.matmul_params(d), flops.head_flops(d)
     attn_per_key = 4 * 24 * 14 * 64
     # 3 prompt tokens see 1 + 2 + 3 keys; one head at the last token

@@ -57,7 +57,8 @@ def test_metrics_for_a_cell():
         layer = {m["name"] for m in registry.metrics_for(
             bench, "per_layer", cell)}
         assert layer == {"slot_occupancy.batch", "mfu.batch",
-                         "device_idle_share.batch"}
+                         "device_idle_share.batch",
+                         "step_calls_per_step.batch"}
     bench["per_layer"].append({"name": "x.other", "workloads": ["c"]})
     assert "x.other" not in {m["name"] for m in registry.metrics_for(
         bench, "per_layer", "qwen2-0.5b.game-batch")}
